@@ -377,6 +377,9 @@ def main(argv=None) -> int:
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.devices}"
             ).strip()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     all_benches = _load_all()
     names = list(all_benches) if args.only == "all" else args.only.split(",")
     t0 = time.time()

@@ -357,7 +357,9 @@ def poisson_loglik(t: SparseTensor, kt: KTensor, eps: float = 1e-10) -> jax.Arra
     prod = jnp.ones((t.values.shape[0], kt.rank), kt.lam.dtype)
     for n, f in enumerate(kt.factors):
         prod = prod * f[t.indices[:, n]]
-    m = prod @ kt.lam
+    # an elementwise f32 sum, not ``prod @ lam``: the TPU runs an f32 matmul
+    # in bf16 passes by default, which rounds every model value
+    m = jnp.sum(prod * kt.lam[None, :], axis=1)
     return jnp.sum(t.values * jnp.log(jnp.maximum(m, eps))) - jnp.sum(kt.lam)
 
 
@@ -678,13 +680,21 @@ def _make_mode_update(
 
         return update, None
 
+    # The mode view and a blocked layout enter the program as arguments,
+    # not closure constants: XLA embeds a closed-over array in the
+    # program, and on TPU in the lane-padded device layout (5.5 GB of
+    # program constants for one mode of chicago at its published nnz).
+    blk = jax.device_put(layout) if isinstance(layout, BlockedLayout) \
+        else None
+
     @jax.jit
-    def update(factors: tuple, lam: jax.Array):
+    def _update(mv: ModeView, blk, factors: tuple, lam: jax.Array):
+        lay = layout if blk is None else blk
         a_n = factors[n]
         # Hoisted gather + layout expansion: once per mode update, shared
         # by the scooch Phi and every fused inner iteration below.
         pi, vals_e, pi_e = hoisted_mode_inputs(mv, factors, strategy,
-                                               layout, pig)
+                                               lay, pig)
 
         # --- scooch: lift inadmissible zeros (Alg. 1 line 3) --------------
         phi0 = phi_from_rows(
@@ -695,7 +705,7 @@ def _make_mode_update(
             n_rows=n_rows,
             eps=cfg.eps,
             strategy=strategy,
-            layout=layout,
+            layout=lay,
             vals_e=vals_e,
             pi_e=pi_e,
             mesh=mesh,
@@ -722,7 +732,7 @@ def _make_mode_update(
                 eps=cfg.eps,
                 tol=cfg.tol,
                 strategy=strategy,
-                layout=layout,
+                layout=lay,
                 vals_e=vals_e,
                 pi_e=pi_e,
                 mesh=mesh,
@@ -742,7 +752,9 @@ def _make_mode_update(
         a_new = b / safe
         return a_new, lam_new, viol, i
 
-    return update, None
+    # update(factors, lam); ``update.func.lower(*update.args, factors, lam)``
+    # lowers the program of one mode update
+    return partial(_update, mv, blk), None
 
 
 def _effective_shard_count(mesh, n_shards) -> int:

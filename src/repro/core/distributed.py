@@ -79,32 +79,6 @@ __all__ = [
 PHI_COMBINES = ("psum", "reduce_scatter")
 
 
-def _resolve_shard_map():
-    """jax.shard_map moved out of jax.experimental; pick whichever exists."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
-def _check_kwarg(sm) -> str:
-    """The replication-check kwarg name for this jax's shard_map
-    (``check_rep`` was renamed ``check_vma``)."""
-    import inspect
-
-    params = inspect.signature(sm).parameters
-    return "check_vma" if "check_vma" in params else "check_rep"
-
-
-def _shard_map(f, mesh, in_specs, out_specs, sm=None):
-    """shard_map with the replication check disabled, on any jax version."""
-    if sm is None:
-        sm = _resolve_shard_map()
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{_check_kwarg(sm): False})
-
-
 # ---------------------------------------------------------------------------
 # Sharded blocked Phi: contiguous row-block shards + one psum combine
 # ---------------------------------------------------------------------------
@@ -244,9 +218,10 @@ def _phi_sharded_buf(slayout: ShardedBlockedLayout, vals_es, pi_es, b,
         p = part(vals_e[0], pi_e[0], lr[0], grb[0], r0[0], bb)
         return jax.lax.psum(p, axes)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local,
-        mesh,
+        mesh=mesh,
+        check_vma=False,
         in_specs=(P(axes, None), P(axes, None, None), P(axes, None),
                   P(axes, None), P(axes), P(None, None)),
         out_specs=P(None, None),
@@ -282,7 +257,8 @@ def _run_sharded(mesh: Mesh | None, shard_fn, sharded_args, bcast_args):
     in_specs = tuple(
         P(axes, *([None] * (a.ndim - 1))) for a in sharded_args
     ) + tuple(P(*([None] * a.ndim)) for a in bcast_args)
-    fn = _shard_map(local, mesh, in_specs=in_specs, out_specs=P(None, None))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(None, None), check_vma=False)
     return fn(*sharded_args, *bcast_args)
 
 
@@ -577,7 +553,8 @@ def _owner_combined(slayout: ShardedBlockedLayout, opart: OwnerPartition,
     out_specs = (
         (P(axes, None, None), P()) if fused else P(axes, None, None)
     )
-    fn = _shard_map(local, mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(*sharded_args)
 
 
@@ -1014,7 +991,8 @@ def _grid_combined(glayout: GridLayout, vals_cs, pi_cs, b_own,
     out_specs = (
         (P(axes, None, None), P()) if fused else P(axes, None, None)
     )
-    fn = _shard_map(local, mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(*sharded_args)
 
 
@@ -1197,8 +1175,8 @@ def _mode_update_dist(mesh: Mesh, cfg: DistCPAPRConfig, n: int, n_rows: int,
         lam_spec,
     )
     out_specs = (f_spec, lam_spec, P(), P())
-    fn = _shard_map(local_update, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs)
+    fn = jax.shard_map(local_update, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 

@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+import jax
 import numpy as np
 
 __all__ = [
@@ -209,6 +210,18 @@ class BlockedLayout:
     @property
     def n_row_blocks(self) -> int:
         return self.n_rows_pad // self.block_rows
+
+
+# A pytree whose leaves are the per-nonzero index arrays, so a jitted caller
+# can take the layout as an argument instead of embedding those arrays in
+# its program as constants (it stays usable as a static jit argument too:
+# the hash is by identity).
+jax.tree_util.register_dataclass(
+    BlockedLayout,
+    data_fields=["gather", "valid", "local_rows", "grid_rb"],
+    meta_fields=["block_nnz", "block_rows", "n_rows", "n_rows_pad", "n_grid",
+                 "pad_fraction"],
+)
 
 
 def build_blocked_layout(

@@ -224,15 +224,19 @@ def _phi_blocked_core(
     else:
         # Gather B windows per grid step: (G, block_rows, R)
         b_blocks = b_win.reshape(n_row_blocks, br, r)[grid_rb]
-        # s = rows of (onehot @ B_window) dotted with pi — both on MXU.
-        b_rows = jnp.einsum("gvb,gbr->gvr", onehot, b_blocks)
+        # s = rows of (onehot @ B_window) dotted with pi — both on MXU, at
+        # the element dtype's full precision (the TPU's default rounds f32
+        # operands to bf16).
+        b_rows = jnp.einsum("gvb,gbr->gvr", onehot, b_blocks,
+                            precision=jax.lax.Precision.HIGHEST)
         s = jnp.sum(b_rows * pi_b, axis=-1)
         w = jnp.where(vals_b > 0, vals_b / jnp.maximum(s, eps), 0.0)
     contrib = w[..., None] * pi_b  # (G, bn, R)
     if perturb == "no_conflict":
         partial_blocks = contrib[:, :br, :]  # uniform write, no keyed reduce
     else:
-        partial_blocks = jnp.einsum("gvb,gvr->gbr", onehot, contrib)
+        partial_blocks = jnp.einsum("gvb,gvr->gbr", onehot, contrib,
+                                    precision=jax.lax.Precision.HIGHEST)
     # Cross-grid-step combine (the "output block revisit" in the kernel):
     phi_blocks = jax.ops.segment_sum(
         partial_blocks, grid_rb, num_segments=n_row_blocks, indices_are_sorted=True

@@ -451,4 +451,4 @@ def model_values_at(kt: KTensor, indices: jax.Array) -> jax.Array:
     prod = jnp.ones((indices.shape[0], kt.rank), kt.lam.dtype)
     for n, f in enumerate(kt.factors):
         prod = prod * f[indices[:, n]]
-    return prod @ kt.lam
+    return jnp.sum(prod * kt.lam[None, :], axis=1)  # f32 on TPU, unlike @

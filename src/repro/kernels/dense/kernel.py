@@ -44,15 +44,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dtypes import contract_precision
+
 __all__ = [
     "dense_mttkrp_pallas_call",
     "dense_phi_pallas_call",
     "dense_phi_mu_pallas_call",
     "KKT_TILE",
 ]
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 # Single KKT partial tile (one row-block window), callers jnp.max it away.
 KKT_TILE = (8, 128)
@@ -72,6 +71,7 @@ def _dense_mttkrp_kernel(x_ref, c_ref, a_ref, out_ref, *, acc_dtype):
         x_ref[...].reshape(bk * i_pad, j_pad),
         c_ref[...],
         preferred_element_type=acc_dtype,
+        precision=contract_precision(c_ref.dtype),
     ).reshape(bk, i_pad, -1)
     t = t * a_ref[...].astype(acc_dtype)[:, None, :]
     out_ref[...] += t.sum(axis=0)
@@ -90,15 +90,18 @@ def _dense_phi_accum(x_ref, c_ref, a_ref, b_ref, *, eps, acc_dtype):
     c = c_ref[...]
     a = a_ref[...]
     b = b_ref[...]
+    prec = contract_precision(c.dtype)
     acc = jnp.zeros((x_ref.shape[1], c_ref.shape[1]), acc_dtype)
     for k in range(block_k):
         a_k = a[k][None, :]  # (1, R) element dtype
         ca = c * a_k  # (J, R)
-        m = jnp.dot(b, ca.T, preferred_element_type=acc_dtype)  # (I, J)
+        m = jnp.dot(b, ca.T, preferred_element_type=acc_dtype,
+                    precision=prec)  # (I, J)
         x_k = x[k].astype(acc_dtype)
         w = jnp.where(x_k > 0, x_k / jnp.maximum(m, eps), 0.0)  # (I, J)
         acc += (
-            jnp.dot(w.astype(c.dtype), c, preferred_element_type=acc_dtype)
+            jnp.dot(w.astype(c.dtype), c, preferred_element_type=acc_dtype,
+                    precision=prec)
             * a_k.astype(acc_dtype)
         )
     return acc
@@ -168,7 +171,7 @@ def _call(kernel, n_grid, block_k, i_pad, j_pad, rank_pad, out_shape,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: output revisiting
         ),
         interpret=interpret,

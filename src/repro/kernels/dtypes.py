@@ -19,9 +19,11 @@ end to end.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["SUPPORTED_KERNEL_DTYPES", "ACC_DTYPE", "check_kernel_dtype"]
+__all__ = ["SUPPORTED_KERNEL_DTYPES", "ACC_DTYPE", "check_kernel_dtype",
+           "contract_precision"]
 
 #: element dtypes the Pallas kernels accept (compute dtype == input dtype)
 SUPPORTED_KERNEL_DTYPES = (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))
@@ -58,3 +60,16 @@ def check_kernel_dtype(name: str, *arrays) -> jnp.dtype:
             f"{[str(d) for d in SUPPORTED_KERNEL_DTYPES]}"
         )
     return dt
+
+
+def contract_precision(dtype):
+    """``precision`` of an in-kernel ``jnp.dot`` over ``dtype`` elements.
+
+    The TPU contracts f32 operands in one bf16 pass unless told otherwise,
+    which put a 1e-3 relative error into Phi on a v5e; f32 elements ask for
+    full f32 contraction.  bf16 elements need nothing: the MXU multiplies
+    them exactly and accumulates in f32.
+    """
+    if jnp.dtype(dtype) == jnp.dtype(jnp.float32):
+        return jax.lax.Precision.HIGHEST
+    return None

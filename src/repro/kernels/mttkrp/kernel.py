@@ -15,10 +15,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["mttkrp_pallas_call"]
+from repro.kernels.dtypes import contract_precision
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+__all__ = ["mttkrp_pallas_call"]
 
 
 def _mttkrp_kernel(
@@ -44,7 +43,8 @@ def _mttkrp_kernel(
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, block_rows), 1)
     onehot = (lrow == row_iota).astype(kr_ref.dtype)
     contrib = vals_ref[...] * kr_ref[...]  # (bn, R)
-    out_ref[...] += jnp.dot(onehot.T, contrib, preferred_element_type=jnp.float32)
+    out_ref[...] += jnp.dot(onehot.T, contrib, preferred_element_type=jnp.float32,
+                            precision=contract_precision(kr_ref.dtype))
 
 
 def mttkrp_pallas_call(
@@ -71,6 +71,6 @@ def mttkrp_pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows_pad, rank_pad), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )

@@ -38,10 +38,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["phi_pallas_call", "phi_mu_pallas_call", "KKT_TILE"]
+from repro.kernels.dtypes import contract_precision
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+__all__ = ["phi_pallas_call", "phi_mu_pallas_call", "KKT_TILE"]
 
 # KKT partials are emitted one (sublane, lane) f32 tile per row block so the
 # output block shape satisfies the TPU minimum tile; callers jnp.max it away.
@@ -75,14 +74,17 @@ def _phi_kernel(
     lrow = lrow_ref[...]  # (bn, 1)
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, block_rows), 1)
     onehot = (lrow == row_iota).astype(pi_ref.dtype)  # (bn, br)
+    prec = contract_precision(pi_ref.dtype)
 
     pi = pi_ref[...]
-    b_rows = jnp.dot(onehot, b_ref[...], preferred_element_type=jnp.float32)
+    b_rows = jnp.dot(onehot, b_ref[...], preferred_element_type=jnp.float32,
+                     precision=prec)
     s = jnp.sum(b_rows * pi, axis=1, keepdims=True)  # (bn, 1)
     vals = vals_ref[...]
     w = jnp.where(vals > 0, vals / jnp.maximum(s, eps), 0.0)  # (bn, 1) f32
     contrib = (w * pi).astype(pi.dtype)  # (bn, R) element dtype
-    phi_ref[...] += jnp.dot(onehot.T, contrib, preferred_element_type=jnp.float32)
+    phi_ref[...] += jnp.dot(onehot.T, contrib,
+                            preferred_element_type=jnp.float32, precision=prec)
 
 
 def _phi_mu_kernel(
@@ -116,15 +118,18 @@ def _phi_mu_kernel(
     lrow = lrow_ref[...]  # (bn, 1)
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, block_rows), 1)
     onehot = (lrow == row_iota).astype(pi_ref.dtype)  # (bn, br)
+    prec = contract_precision(pi_ref.dtype)
 
     pi = pi_ref[...]
     b = b_ref[...]
-    b_rows = jnp.dot(onehot, b, preferred_element_type=jnp.float32)
+    b_rows = jnp.dot(onehot, b, preferred_element_type=jnp.float32,
+                     precision=prec)
     s = jnp.sum(b_rows * pi, axis=1, keepdims=True)  # (bn, 1)
     vals = vals_ref[...]
     w = jnp.where(vals > 0, vals / jnp.maximum(s, eps), 0.0)  # (bn, 1) f32
     contrib = (w * pi).astype(pi.dtype)  # (bn, R) element dtype
-    mu_ref[...] += jnp.dot(onehot.T, contrib, preferred_element_type=jnp.float32)
+    mu_ref[...] += jnp.dot(onehot.T, contrib,
+                           preferred_element_type=jnp.float32, precision=prec)
 
     # Fused epilogue: the accumulated Phi window never leaves VMEM — it is
     # consumed in place by the KKT partial reduce and the MU product.
@@ -169,7 +174,7 @@ def phi_pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows_pad, rank_pad), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: output revisiting
         ),
         interpret=interpret,
@@ -222,7 +227,7 @@ def phi_mu_pallas_call(
             jax.ShapeDtypeStruct((n_rows_pad, rank_pad), jnp.float32),
             jax.ShapeDtypeStruct((n_rb * KKT_TILE[0], KKT_TILE[1]), jnp.float32),
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: output revisiting
         ),
         interpret=interpret,

@@ -17,20 +17,22 @@ from __future__ import annotations
 
 import argparse
 import sys
-import tempfile
 import time
 
 import jax
 
+from repro.compile_cache import REPO_ROOT, enable_compile_cache
 from repro.config import ShapeConfig
 from repro.configs import get_arch, reduced
 from repro.models.api import build_model
 from repro.serve.engine import Engine, ServeConfig
 
+# The decomp launcher's shared autotune store: a fixed path in the checkout,
+# so a second run serves the first run's winners.
+DEFAULT_AUTOTUNE_STORE = REPO_ROOT / ".autotune" / "serve.json"
+
 
 def main_decomp(argv=None):
-    import os
-
     import numpy as np
 
     from repro.core.cpapr import CPAPRConfig, cpapr_mu
@@ -47,15 +49,15 @@ def main_decomp(argv=None):
                     help="appended nonzeros as a fraction of the tensor")
     ap.add_argument("--max-outer", type=int, default=40)
     ap.add_argument("--tol", type=float, default=1e-2)
-    ap.add_argument("--autotune-cache", default=None,
-                    help="shared store path (default: a temp file)")
+    ap.add_argument("--autotune-cache", default=str(DEFAULT_AUTOTUNE_STORE),
+                    help="shared autotune store path")
     ap.add_argument("--seed", type=int, default=1)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     print(f"[decomp] devices={jax.device_count()} "
           f"backend={jax.default_backend()}")
-    cache = args.autotune_cache or os.path.join(
-        tempfile.mkdtemp(prefix="repro-serve-"), "autotune.json")
+    cache = args.autotune_cache
     svc = DecompService(autotune_path=cache, max_outer=args.max_outer,
                         tol=args.tol)
 
@@ -119,6 +121,7 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if not args.full:

@@ -80,30 +80,40 @@ def attainable_gflops(intensity: float, hw: HardwareSpec) -> float:
     return min(hw.peak_flops, hw.hbm_bw * intensity) / 1e9
 
 
-# jax backend platform -> HARDWARE key.  "gpu" maps to the paper's K80
-# spec (the only GPU we have numbers for); real deployments override via
-# $REPRO_HARDWARE_SPEC.
-_BACKEND_SPECS = {"cpu": "host_cpu", "tpu": "tpu_v5e", "gpu": "k80"}
+# jax ``device_kind`` -> HARDWARE key.  A device that is not listed has no
+# peaks to score against, so detection raises instead of guessing.
+_DEVICE_KIND_SPECS = {"TPU v5 lite": "tpu_v5e", "Tesla K80": "k80"}
 
 
-def detect_hardware_spec(platform: str | None = None) -> HardwareSpec:
-    """HardwareSpec for the *actual* backend, not an assumed TPU.
+def detect_hardware_spec(platform: str | None = None,
+                         device_kind: str | None = None) -> HardwareSpec:
+    """HardwareSpec for the *actual* device, keyed by its ``device_kind``.
 
-    Resolution order: ``$REPRO_HARDWARE_SPEC`` (a HARDWARE key), the
-    ``platform`` argument, then ``jax.default_backend()``.  Unknown
-    platforms fall back to ``host_cpu`` — a wrong-but-finite bound beats
-    a KeyError in the middle of an autotune pass.
+    Resolution order: ``$REPRO_HARDWARE_SPEC`` (a HARDWARE key), then the
+    ``platform`` argument or ``jax.default_backend()``: ``"cpu"`` is the
+    host spec; any other platform is looked up by ``device_kind`` (default:
+    that of the platform's first device).  An unknown kind raises
+    ``ValueError``.
     """
     import os
 
     override = os.environ.get("REPRO_HARDWARE_SPEC")
     if override and override in HARDWARE:
         return HARDWARE[override]
-    if platform is None:
-        import jax
+    import jax
 
+    if platform is None:
         platform = jax.default_backend()
-    return HARDWARE[_BACKEND_SPECS.get(platform, "host_cpu")]
+    if platform == "cpu":
+        return HARDWARE["host_cpu"]
+    if device_kind is None:
+        device_kind = jax.devices(platform)[0].device_kind
+    if device_kind not in _DEVICE_KIND_SPECS:
+        raise ValueError(
+            f"no hardware spec for {platform} device kind {device_kind!r}; "
+            f"known kinds: {sorted(_DEVICE_KIND_SPECS)}"
+        )
+    return HARDWARE[_DEVICE_KIND_SPECS[device_kind]]
 
 
 # The intensities the paper *states* (Eq. 5 / Eq. 8, FLOP/byte).  Note:

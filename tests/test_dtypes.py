@@ -109,7 +109,7 @@ def test_sparse_strategies_carry_f64(strategy):
     """f64 in, f64 out — and genuinely double precision, not an upcast
     of an f32 intermediate: the result matches the f64 oracle far
     below f32 resolution."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rows, vals, pi, b = _problem(jnp.float64)
         kw = _strategy_kwargs(strategy, rows, vals, pi, b)
         out = phi_from_rows(jnp.asarray(rows), vals, pi, b, N_ROWS,
@@ -124,7 +124,7 @@ def test_sparse_strategies_carry_f64(strategy):
 def test_kernel_strategies_raise_on_f64(strategy):
     """No silent downcast: the Pallas tiers refuse f64 with a pointer at
     the jnp strategies instead of handing back f32."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rows, vals, pi, b = _problem(jnp.float64)
         kw = _strategy_kwargs(strategy, rows, vals, pi, b)
         with pytest.raises(ValueError, match="float64"):
@@ -138,7 +138,7 @@ def test_kernel_entry_points_raise_on_f64():
     from repro.kernels.dense import mttkrp_dense, phi_dense
     from repro.kernels.stream.ops import stream_op
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x = jnp.ones((2, 4, 4), jnp.float64)
         c = jnp.ones((4, 3), jnp.float64)
         a = jnp.ones((2, 3), jnp.float64)

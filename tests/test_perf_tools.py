@@ -89,3 +89,39 @@ def test_frostt_tensors_shapes():
     assert t.shape == FROSTT["uber"][0]
     assert t.nnz >= 1000
     assert float(t.values.min()) > 0
+
+
+def test_make_tensor_is_the_same_in_every_process():
+    """The draw is a function of (name, scale, rank, seed) alone: Python
+    salts ``hash`` per process, so a seed built from it drew a new tensor
+    every run."""
+    import hashlib
+    import os
+    import subprocess
+    import sys
+
+    script = (
+        "import hashlib, numpy as np\n"
+        "from repro.data.tensors import make_tensor\n"
+        "t, _ = make_tensor('uber', scale=0.001, seed=3)\n"
+        "h = hashlib.sha256(np.asarray(t.indices).tobytes()"
+        " + np.asarray(t.values).tobytes())\n"
+        "print(t.nnz, h.hexdigest())\n"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    outs = []
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, PYTHONPATH=src, PYTHONHASHSEED=hash_seed,
+                   JAX_PLATFORMS="cpu")
+        out = subprocess.run([sys.executable, "-c", script], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        outs.append(out.stdout.split()[-2:])
+    assert outs[0] == outs[1]
+    t, _ = make_tensor("uber", scale=0.001, seed=3)
+    h = hashlib.sha256(np.asarray(t.indices).tobytes()
+                       + np.asarray(t.values).tobytes())
+    assert outs[0] == [str(t.nnz), h.hexdigest()]
+    other, _ = make_tensor("uber", scale=0.001, seed=4)
+    assert not np.array_equal(np.asarray(other.indices),
+                              np.asarray(t.indices))

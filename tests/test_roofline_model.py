@@ -76,19 +76,32 @@ def test_roofline_terms_direct_construction_default_peak():
 def test_detect_hardware_spec_resolution(monkeypatch):
     monkeypatch.delenv("REPRO_HARDWARE_SPEC", raising=False)
     assert detect_hardware_spec("cpu") is HARDWARE["host_cpu"]
-    assert detect_hardware_spec("tpu") is HARDWARE["tpu_v5e"]
-    assert detect_hardware_spec("gpu") is HARDWARE["k80"]
-    # unknown platform: wrong-but-finite beats KeyError mid-autotune
-    assert detect_hardware_spec("rocm") is HARDWARE["host_cpu"]
+    # accelerators are keyed by device_kind, as jax reports it
+    assert detect_hardware_spec("tpu", device_kind="TPU v5 lite") \
+        is HARDWARE["tpu_v5e"]
+    assert detect_hardware_spec("gpu", device_kind="Tesla K80") \
+        is HARDWARE["k80"]
     # no argument: resolves the real backend without raising
     assert detect_hardware_spec() in HARDWARE.values()
+
+
+@pytest.mark.parametrize("platform,kind", [
+    ("tpu", "TPU v4"), ("tpu", "TPU v99"), ("gpu", "NVIDIA H100"),
+])
+def test_detect_hardware_spec_unknown_kind_raises(monkeypatch, platform,
+                                                  kind):
+    """An unlisted device has no peaks: detection refuses to guess."""
+    monkeypatch.delenv("REPRO_HARDWARE_SPEC", raising=False)
+    with pytest.raises(ValueError, match="device kind"):
+        detect_hardware_spec(platform, device_kind=kind)
 
 
 def test_detect_hardware_spec_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_HARDWARE_SPEC", "e5_2690v4_dual")
     assert detect_hardware_spec("tpu") is HARDWARE["e5_2690v4_dual"]
     monkeypatch.setenv("REPRO_HARDWARE_SPEC", "not_a_spec")
-    assert detect_hardware_spec("gpu") is HARDWARE["k80"]
+    assert detect_hardware_spec("gpu", device_kind="Tesla K80") \
+        is HARDWARE["k80"]
 
 
 # ---------------------------------------------------------------------------

@@ -188,38 +188,6 @@ def test_cpapr_sharded_falls_back_with_warning(small_tensor):
 
 
 # ---------------------------------------------------------------------------
-# _shard_map compat shim (check_rep -> check_vma rename)
-# ---------------------------------------------------------------------------
-
-
-def test_shard_map_check_kwarg_shim():
-    from repro.core.distributed import (
-        _check_kwarg,
-        _resolve_shard_map,
-        _shard_map,
-    )
-
-    captured = {}
-
-    def fake_vma(f, *, mesh, in_specs, out_specs, check_vma=True):
-        captured["kw"] = ("check_vma", check_vma)
-        return f
-
-    def fake_rep(f, *, mesh, in_specs, out_specs, check_rep=True):
-        captured["kw"] = ("check_rep", check_rep)
-        return f
-
-    assert _check_kwarg(fake_vma) == "check_vma"
-    assert _check_kwarg(fake_rep) == "check_rep"
-    _shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=(), sm=fake_vma)
-    assert captured["kw"] == ("check_vma", False)
-    _shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=(), sm=fake_rep)
-    assert captured["kw"] == ("check_rep", False)
-    # the real jax shard_map resolves and takes one of the two kwargs
-    assert _check_kwarg(_resolve_shard_map()) in ("check_vma", "check_rep")
-
-
-# ---------------------------------------------------------------------------
 # Real-mesh equivalence + collective accounting (forced-device subprocesses)
 # ---------------------------------------------------------------------------
 
