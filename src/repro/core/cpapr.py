@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from functools import partial
 from typing import Sequence
 
@@ -44,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import resilience
+from . import resilience, trace
 from .layout import (
     BlockedLayout,
     GridLayout,
@@ -140,9 +139,10 @@ class CPAPRConfig:
     # Reject NaN/negative values, out-of-range indices, and rank <= 0 at
     # the solve boundary (one host pass over the nonzeros).
     validate: bool = True
-    # Numerical guard: a fused finite/positivity reduction on (A_n', lam)
-    # inside each mode update's jit (no host sync beyond the one the
-    # solver already does on the KKT scalar).  On violation the last-good
+    # Numerical guard: a finite/positivity reduction on (A_n', lam) after
+    # each mode update, its own small dispatch; the sweep reads each
+    # mode's KKT scalar on the host as the mode completes, and the guard
+    # flags at sweep end (host syncs).  On violation the last-good
     # state is restored and the mode retried — once as-is (transient
     # fault), then with the scooch kappa escalated 10x per further retry
     # (the kappa ladder) — before giving up after guard_retries.
@@ -173,7 +173,6 @@ class CPAPRResult:
     loglik_history: list
     inner_iters: list  # per outer iter: total inner iterations
     converged: bool
-    seconds: float
     policies: list | None = None  # per-mode PhiPolicy when policy="auto"
     # per rebalance event: {"outer", "mode", "rb_start_old", "rb_start_new",
     # "imbalance_old", "imbalance_new"} (nnz max/mean over shards)
@@ -202,7 +201,8 @@ class SweepOutcome:
     bad: list
 
 
-def sweep_step(carry, batch, guard: bool = False) -> SweepOutcome:
+def sweep_step(carry, batch, guard: bool = False,
+               counters: "trace.Counters | None" = None) -> SweepOutcome:
     """One CP-APR outer sweep as a pure ``(carry, batch) -> carry`` step.
 
     ``carry`` is ``(factors, lam)``; ``batch`` is the sweep's worth of
@@ -222,7 +222,10 @@ def sweep_step(carry, batch, guard: bool = False) -> SweepOutcome:
     that finishes collects every tripped mode into ``bad``.  The input
     ``factors`` list is never mutated — the outcome carries a fresh list,
     so the caller's sweep-start snapshot stays intact for guard restores.
+    The guard's host reads (each mode's KKT scalar, then the flags) count
+    as ``host_syncs`` on ``counters``.
     """
+    counters = counters if counters is not None else trace.Counters()
     factors, lam = list(carry[0]), carry[1]
     n_modes = len(batch)
     worst = None
@@ -231,14 +234,13 @@ def sweep_step(carry, batch, guard: bool = False) -> SweepOutcome:
     bad: list = []
     for n, mode_fn in enumerate(batch):
         a_new, lam_new, viol, n_inner, ok = mode_fn(factors, lam)
-        if guard and not math.isfinite(float(jnp.max(viol))):
+        if guard and not math.isfinite(counters.read(float, jnp.max(viol))):
             # poisoned KKT scalar: no point finishing the sweep, the
             # remaining modes would consume NaN factors.  Blame an
             # earlier mode whose (complete) guard flag tripped — its bad
             # factors poisoned this one.
-            bad = [m for m in range(n)
-                   if ok_flags[m] is not None and not bool(ok_flags[m])] \
-                or [n]
+            bad = [m for m in range(n) if ok_flags[m] is not None
+                   and not counters.read(bool, ok_flags[m])] or [n]
             break
         factors[n] = a_new
         lam = lam_new
@@ -246,8 +248,8 @@ def sweep_step(carry, batch, guard: bool = False) -> SweepOutcome:
         worst = viol if worst is None else jnp.maximum(worst, viol)
         inner_total = inner_total + n_inner
     if guard and not bad:
-        bad = [n for n in range(n_modes)
-               if ok_flags[n] is not None and not bool(ok_flags[n])]
+        bad = [n for n in range(n_modes) if ok_flags[n] is not None
+               and not counters.read(bool, ok_flags[n])]
     return SweepOutcome(factors=factors, lam=lam, worst=worst,
                         inner_total=inner_total, bad=bad)
 
@@ -275,20 +277,23 @@ def hoisted_mode_inputs(mv: ModeView, factors, strategy: str, layout, pig):
     if pig is not None:
         # Shard-local Pi: only the values expansion is hoisted (the
         # factor-row gathers happen per call inside the sharded reduce).
-        return None, expand_vals_to_shards(layout, mv.sorted_vals), None
+        with jax.named_scope("cpapr.layout"):
+            return None, expand_vals_to_shards(layout, mv.sorted_vals), None
     if strategy == "dense":
         # The dense tier never builds Pi or a sorted-stream expansion —
         # its hoisted state is the DenseModeData riding the layout slot.
         return None, None, None
-    pi = pi_rows(mv.sorted_idx, factors, mv.mode)
-    if strategy == "grid" and isinstance(layout, GridLayout):
-        vals_e, pi_e = expand_to_grid(layout, mv.sorted_vals, pi)
-    elif strategy == "sharded" and layout is not None:
-        vals_e, pi_e = expand_to_shards(layout, mv.sorted_vals, pi)
-    elif strategy in ("blocked", "pallas") and layout is not None:
-        vals_e, pi_e = expand_to_layout(layout, mv.sorted_vals, pi)
-    else:
-        vals_e = pi_e = None
+    with jax.named_scope("cpapr.pi"):
+        pi = pi_rows(mv.sorted_idx, factors, mv.mode)
+    with jax.named_scope("cpapr.layout"):
+        if strategy == "grid" and isinstance(layout, GridLayout):
+            vals_e, pi_e = expand_to_grid(layout, mv.sorted_vals, pi)
+        elif strategy == "sharded" and layout is not None:
+            vals_e, pi_e = expand_to_shards(layout, mv.sorted_vals, pi)
+        elif strategy in ("blocked", "pallas") and layout is not None:
+            vals_e, pi_e = expand_to_layout(layout, mv.sorted_vals, pi)
+        else:
+            vals_e = pi_e = None
     return pi, vals_e, pi_e
 
 
@@ -470,19 +475,22 @@ def _make_owner_mode_update(
         a_n = factors[n]
         _, vals_e, pi_e = hoisted_mode_inputs(mv, factors, "sharded",
                                               layout, pig)
-        a_own = owner_stack(opart, a_n)
+        with jax.named_scope("cpapr.layout"):
+            a_own = owner_stack(opart, a_n)
         lam_b = lam[None, None, :]
 
         # --- scooch: lift inadmissible zeros (Alg. 1 line 3), owner-local
-        phi0_own = phi_sharded_owner(
-            layout, opart, vals_e, pi_e, a_own * lam_b,
-            eps=cfg.eps, mesh=mesh, local_strategy=local_strategy,
-            pi_gather=pig,
-            factors=factors if pig is not None else None,
-        )
-        s = jnp.where((a_own < cfg.kappa_tol) & (phi0_own > 1.0),
-                      cfg.kappa, 0.0)
-        b0_own = (a_own + s) * lam_b
+        with jax.named_scope("cpapr.phi"):
+            phi0_own = phi_sharded_owner(
+                layout, opart, vals_e, pi_e, a_own * lam_b,
+                eps=cfg.eps, mesh=mesh, local_strategy=local_strategy,
+                pi_gather=pig,
+                factors=factors if pig is not None else None,
+            )
+        with jax.named_scope("cpapr.epilogue"):
+            s = jnp.where((a_own < cfg.kappa_tol) & (phi0_own > 1.0),
+                          cfg.kappa, 0.0)
+            b0_own = (a_own + s) * lam_b
 
         # --- fused inner MU loop (Alg. 1 lines 5-8), owner-stacked carry
         def cond(state):
@@ -491,18 +499,20 @@ def _make_owner_mode_update(
 
         def body(state):
             i, b_own, _ = state
-            b_new, viol = phi_mu_sharded_owner(
-                layout, opart, vals_e, pi_e, b_own,
-                eps=cfg.eps, tol=cfg.tol, mesh=mesh,
-                local_strategy=local_strategy, pi_gather=pig,
-                factors=factors if pig is not None else None,
-            )
+            with jax.named_scope("cpapr.phi"):
+                b_new, viol = phi_mu_sharded_owner(
+                    layout, opart, vals_e, pi_e, b_own,
+                    eps=cfg.eps, tol=cfg.tol, mesh=mesh,
+                    local_strategy=local_strategy, pi_gather=pig,
+                    factors=factors if pig is not None else None,
+                )
             return (i + 1, b_new, viol)
 
-        i, b_own, viol = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), b0_own,
-                         jnp.asarray(jnp.inf, b0_own.dtype))
-        )
+        with jax.named_scope("cpapr.epilogue"):  # the loop's own KKT test
+            i, b_own, viol = jax.lax.while_loop(
+                cond, body, (jnp.int32(0), b0_own,
+                             jnp.asarray(jnp.inf, b0_own.dtype))
+            )
         return b_own, viol, i
 
     @jax.jit
@@ -510,10 +520,12 @@ def _make_owner_mode_update(
         # --- renormalize (Alg. 1 lines 9-10) on the reassembled factor.
         # Under a mesh the stacked carry is device-sharded, so this is
         # the once-per-mode-update all-gather of the updated rows.
-        b = owner_unstack(opart, b_own)
-        lam_new = jnp.sum(b, axis=0)
-        safe = jnp.maximum(lam_new, cfg.eps)
-        a_new = b / safe
+        with jax.named_scope("cpapr.layout"):
+            b = owner_unstack(opart, b_own)
+        with jax.named_scope("cpapr.epilogue"):
+            lam_new = jnp.sum(b, axis=0)
+            safe = jnp.maximum(lam_new, cfg.eps)
+            a_new = b / safe
         return a_new, lam_new
 
     return update, gather
@@ -551,17 +563,20 @@ def _make_grid_mode_update(
         a_n = factors[n]
         _, vals_e, pi_e = hoisted_mode_inputs(mv, factors, "grid",
                                               glayout, None)
-        a_own = grid_stack(glayout, a_n)
+        with jax.named_scope("cpapr.layout"):
+            a_own = grid_stack(glayout, a_n)
         lam_b = lam[None, None, :]
 
         # --- scooch: lift inadmissible zeros (Alg. 1 line 3), grid-local
-        phi0_own = phi_grid_owner(
-            glayout, vals_e, pi_e, a_own * lam_b,
-            eps=cfg.eps, mesh=mesh, local_strategy=local_strategy,
-        )
-        s = jnp.where((a_own < cfg.kappa_tol) & (phi0_own > 1.0),
-                      cfg.kappa, 0.0)
-        b0_own = (a_own + s) * lam_b
+        with jax.named_scope("cpapr.phi"):
+            phi0_own = phi_grid_owner(
+                glayout, vals_e, pi_e, a_own * lam_b,
+                eps=cfg.eps, mesh=mesh, local_strategy=local_strategy,
+            )
+        with jax.named_scope("cpapr.epilogue"):
+            s = jnp.where((a_own < cfg.kappa_tol) & (phi0_own > 1.0),
+                          cfg.kappa, 0.0)
+            b0_own = (a_own + s) * lam_b
 
         # --- fused inner MU loop (Alg. 1 lines 5-8), grid-stacked carry
         def cond(state):
@@ -570,26 +585,30 @@ def _make_grid_mode_update(
 
         def body(state):
             i, b_own, _ = state
-            b_new, viol = phi_mu_grid_owner(
-                glayout, vals_e, pi_e, b_own,
-                eps=cfg.eps, tol=cfg.tol, mesh=mesh,
-                local_strategy=local_strategy,
-            )
+            with jax.named_scope("cpapr.phi"):
+                b_new, viol = phi_mu_grid_owner(
+                    glayout, vals_e, pi_e, b_own,
+                    eps=cfg.eps, tol=cfg.tol, mesh=mesh,
+                    local_strategy=local_strategy,
+                )
             return (i + 1, b_new, viol)
 
-        i, b_own, viol = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), b0_own,
-                         jnp.asarray(jnp.inf, b0_own.dtype))
-        )
+        with jax.named_scope("cpapr.epilogue"):  # the loop's own KKT test
+            i, b_own, viol = jax.lax.while_loop(
+                cond, body, (jnp.int32(0), b0_own,
+                             jnp.asarray(jnp.inf, b0_own.dtype))
+            )
         return b_own, viol, i
 
     @jax.jit
     def gather(b_own: jax.Array):
         # --- renormalize (Alg. 1 lines 9-10) on the reassembled factor.
-        b = grid_unstack(glayout, b_own)
-        lam_new = jnp.sum(b, axis=0)
-        safe = jnp.maximum(lam_new, cfg.eps)
-        a_new = b / safe
+        with jax.named_scope("cpapr.layout"):
+            b = grid_unstack(glayout, b_own)
+        with jax.named_scope("cpapr.epilogue"):
+            lam_new = jnp.sum(b, axis=0)
+            safe = jnp.maximum(lam_new, cfg.eps)
+            a_new = b / safe
         return a_new, lam_new
 
     return update, gather
@@ -645,15 +664,18 @@ def _make_mode_update(
             # factor-side operands (c, a) are hoisted out of the inner
             # loop — they depend only on the non-target factors.
             a_n = factors[n]
-            xx, c, a = _dense_operands(dense.with_x(x), factors, a_n)
+            with jax.named_scope("cpapr.pi"):
+                xx, c, a = _dense_operands(dense.with_x(x), factors, a_n)
 
             # --- scooch: lift inadmissible zeros (Alg. 1 line 3) ----------
-            phi0 = dense_ops.phi_dense(
-                xx, c, a, a_n * lam[None, :], eps=cfg.eps
-            )
-            s = jnp.where((a_n < cfg.kappa_tol) & (phi0 > 1.0),
-                          cfg.kappa, 0.0)
-            b0 = (a_n + s) * lam[None, :]
+            with jax.named_scope("cpapr.phi"):
+                phi0 = dense_ops.phi_dense(
+                    xx, c, a, a_n * lam[None, :], eps=cfg.eps
+                )
+            with jax.named_scope("cpapr.epilogue"):
+                s = jnp.where((a_n < cfg.kappa_tol) & (phi0 > 1.0),
+                              cfg.kappa, 0.0)
+                b0 = (a_n + s) * lam[None, :]
 
             # --- fused inner MU loop (Alg. 1 lines 5-8) -------------------
             def cond(state):
@@ -662,18 +684,24 @@ def _make_mode_update(
 
             def body(state):
                 i, b, _ = state
-                mu, viol = dense_ops.phi_mu_dense(xx, c, a, b, eps=cfg.eps)
-                return (i + 1, jnp.where(viol > cfg.tol, mu, b), viol)
+                with jax.named_scope("cpapr.phi"):
+                    mu, viol = dense_ops.phi_mu_dense(xx, c, a, b,
+                                                      eps=cfg.eps)
+                with jax.named_scope("cpapr.epilogue"):
+                    b = jnp.where(viol > cfg.tol, mu, b)
+                return (i + 1, b, viol)
 
-            i, b, viol = jax.lax.while_loop(
-                cond, body,
-                (jnp.int32(0), b0, jnp.asarray(jnp.inf, jnp.float32)),
-            )
+            with jax.named_scope("cpapr.epilogue"):  # the loop's KKT test
+                i, b, viol = jax.lax.while_loop(
+                    cond, body,
+                    (jnp.int32(0), b0, jnp.asarray(jnp.inf, jnp.float32)),
+                )
 
             # --- renormalize (Alg. 1 lines 9-10) --------------------------
-            lam_new = jnp.sum(b, axis=0)
-            safe = jnp.maximum(lam_new, cfg.eps)
-            return b / safe, lam_new, viol, i
+            with jax.named_scope("cpapr.epilogue"):
+                lam_new = jnp.sum(b, axis=0)
+                safe = jnp.maximum(lam_new, cfg.eps)
+                return b / safe, lam_new, viol, i
 
         def update(factors: tuple, lam: jax.Array):
             return _dense_update(dense.x, tuple(factors), lam)
@@ -697,40 +725,14 @@ def _make_mode_update(
                                                lay, pig)
 
         # --- scooch: lift inadmissible zeros (Alg. 1 line 3) --------------
-        phi0 = phi_from_rows(
-            mv.rows,
-            mv.sorted_vals,
-            pi,
-            a_n * lam[None, :],
-            n_rows=n_rows,
-            eps=cfg.eps,
-            strategy=strategy,
-            layout=lay,
-            vals_e=vals_e,
-            pi_e=pi_e,
-            mesh=mesh,
-            local_strategy=local_strategy,
-            pi_gather=pig,
-            factors=factors if pig is not None else None,
-        )
-        s = jnp.where((a_n < cfg.kappa_tol) & (phi0 > 1.0), cfg.kappa, 0.0)
-        b0 = (a_n + s) * lam[None, :]
-
-        # --- fused inner MU loop (Alg. 1 lines 5-8) ------------------------
-        def cond(state):
-            i, _, viol = state
-            return (i < cfg.max_inner) & (viol > cfg.tol)
-
-        def body(state):
-            i, b, _ = state
-            b_new, viol = phi_mu_step(
+        with jax.named_scope("cpapr.phi"):
+            phi0 = phi_from_rows(
                 mv.rows,
                 mv.sorted_vals,
                 pi,
-                b,
+                a_n * lam[None, :],
                 n_rows=n_rows,
                 eps=cfg.eps,
-                tol=cfg.tol,
                 strategy=strategy,
                 layout=lay,
                 vals_e=vals_e,
@@ -740,16 +742,50 @@ def _make_mode_update(
                 pi_gather=pig,
                 factors=factors if pig is not None else None,
             )
+        with jax.named_scope("cpapr.epilogue"):
+            s = jnp.where((a_n < cfg.kappa_tol) & (phi0 > 1.0),
+                          cfg.kappa, 0.0)
+            b0 = (a_n + s) * lam[None, :]
+
+        # --- fused inner MU loop (Alg. 1 lines 5-8) ------------------------
+        def cond(state):
+            i, _, viol = state
+            return (i < cfg.max_inner) & (viol > cfg.tol)
+
+        def body(state):
+            i, b, _ = state
+            # phi_mu_step names its own layout and epilogue ops
+            with jax.named_scope("cpapr.phi"):
+                b_new, viol = phi_mu_step(
+                    mv.rows,
+                    mv.sorted_vals,
+                    pi,
+                    b,
+                    n_rows=n_rows,
+                    eps=cfg.eps,
+                    tol=cfg.tol,
+                    strategy=strategy,
+                    layout=lay,
+                    vals_e=vals_e,
+                    pi_e=pi_e,
+                    mesh=mesh,
+                    local_strategy=local_strategy,
+                    pi_gather=pig,
+                    factors=factors if pig is not None else None,
+                )
             return (i + 1, b_new, viol)
 
-        i, b, viol = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), b0, jnp.asarray(jnp.inf, b0.dtype))
-        )
+        with jax.named_scope("cpapr.epilogue"):  # the loop's own KKT test
+            i, b, viol = jax.lax.while_loop(
+                cond, body,
+                (jnp.int32(0), b0, jnp.asarray(jnp.inf, b0.dtype)),
+            )
 
         # --- renormalize (Alg. 1 lines 9-10) -------------------------------
-        lam_new = jnp.sum(b, axis=0)
-        safe = jnp.maximum(lam_new, cfg.eps)
-        a_new = b / safe
+        with jax.named_scope("cpapr.epilogue"):
+            lam_new = jnp.sum(b, axis=0)
+            safe = jnp.maximum(lam_new, cfg.eps)
+            a_new = b / safe
         return a_new, lam_new, viol, i
 
     # update(factors, lam); ``update.func.lower(*update.args, factors, lam)``
@@ -1135,95 +1171,125 @@ def cpapr_mu(
     identically to the uninterrupted run; a corrupt or mismatched
     checkpoint is quarantined (recorded in ``result.recoveries``) and the
     solve starts fresh instead of dying.
+
+    The call is the host span ``cpapr.solve``; at its end it carries the
+    attributes ``modes``, ``sweeps`` and ``inner`` (the sweeps and inner
+    iterations this call ran) and ``host_syncs`` (device values the sweep
+    loop read on the host).  Inside it: ``cpapr.prepare`` (with
+    ``cpapr.validate``, ``cpapr.sort``, ``cpapr.policy``, ``cpapr.build``),
+    then per sweep ``cpapr.sweep`` (with ``cpapr.mode_update`` and
+    ``cpapr.loglik``), and ``cpapr.rebalance``, ``cpapr.checkpoint`` and
+    ``cpapr.recover`` where those paths run.
     """
+    counters = trace.Counters()
+    with trace.counted("cpapr.solve", counters, modes=t.ndim):
+        return _cpapr_mu(t, rank, key, init, config, mode_views,
+                         resume_from, counters)
+
+
+def _cpapr_mu(t, rank, key, init, config, mode_views, resume_from,
+              counters: trace.Counters) -> CPAPRResult:
     cfg = config or CPAPRConfig(rank=rank)
     assert cfg.rank == rank
-    if cfg.validate:
-        resilience.validate_decomposition_inputs(t, rank, where="cpapr_mu")
     n_modes = t.ndim
-    if init is None:
-        key = key if key is not None else jax.random.PRNGKey(0)
-        init = random_ktensor(key, t.shape, rank)
-    kt = init.normalize()
-    factors = list(kt.factors)
-    lam = kt.lam
+    with trace.span("cpapr.prepare"):
+        if cfg.validate:
+            with trace.span("cpapr.validate"):
+                resilience.validate_decomposition_inputs(t, rank,
+                                                         where="cpapr_mu")
+        if init is None:
+            key = key if key is not None else jax.random.PRNGKey(0)
+            init = random_ktensor(key, t.shape, rank)
+        kt = init.normalize()
+        factors = list(kt.factors)
+        lam = kt.lam
 
-    mvs = list(mode_views) if mode_views is not None else [
-        sort_mode(t, n) for n in range(n_modes)
-    ]
+        if mode_views is not None:
+            mvs = list(mode_views)
+        else:
+            mvs = []
+            for n in range(n_modes):
+                with trace.span("cpapr.sort", mode=n):
+                    mvs.append(sort_mode(t, n))
 
-    recoveries: list = []
-    fp = _ckpt_fingerprint(t, cfg)
-    resume_state = None
-    if resume_from is not None:
-        try:
-            resume_state = resilience.load_checkpoint(resume_from)
-            if resume_state.get("fingerprint") != fp:
-                raise resilience.CheckpointError(
-                    f"{resume_from}: checkpoint fingerprint "
-                    f"{resume_state.get('fingerprint')!r} does not match "
-                    f"this problem/config ({fp!r})"
+        recoveries: list = []
+        fp = _ckpt_fingerprint(t, cfg)
+        resume_state = None
+        if resume_from is not None:
+            try:
+                with trace.span("cpapr.recover"):
+                    resume_state = resilience.load_checkpoint(resume_from)
+                if resume_state.get("fingerprint") != fp:
+                    raise resilience.CheckpointError(
+                        f"{resume_from}: checkpoint fingerprint "
+                        f"{resume_state.get('fingerprint')!r} does not "
+                        f"match this problem/config ({fp!r})"
+                    )
+            except resilience.CheckpointError as e:
+                qpath = resilience.quarantine_checkpoint(resume_from)
+                recoveries.append(RecoveryEvent(
+                    "checkpoint_corrupt", outer=0,
+                    detail={"error": str(e), "quarantined": qpath},
+                ))
+                resume_state = None
+
+        start_outer = 0
+        kkt_hist: list = []
+        ll_hist: list = []
+        inner_hist: list = []
+        rebalances: list = []
+        if resume_state is None:
+            with trace.span("cpapr.policy"):
+                strategies, layouts, policies, locals_ = \
+                    _resolve_mode_policies(cfg, mvs, factors, lam)
+            # per-mode effective config: the kappa ladder and the
+            # combine demotion mutate these without touching the cfg
+            mode_cfgs = [cfg] * n_modes
+        else:
+            start_outer = int(resume_state["outer"])
+            factors = [jnp.asarray(f) for f in resume_state["factors"]]
+            lam = jnp.asarray(resume_state["lam"])
+            strategies = list(resume_state["strategies"])
+            locals_ = list(resume_state["locals"])
+            policies = [PhiPolicy(**p) if p else None
+                        for p in resume_state["policies"]]
+            rb_bounds = {int(k): v for k, v in
+                         resume_state.get("rb_bounds", {}).items()}
+            with trace.span("cpapr.policy"):
+                layouts = _restore_mode_layouts(
+                    mvs, strategies, policies,
+                    list(resume_state["mode_shards"]), rb_bounds,
+                    shape=t.shape, mode_grids=resume_state.get("mode_grids"),
                 )
-        except resilience.CheckpointError as e:
-            qpath = resilience.quarantine_checkpoint(resume_from)
+            # restore the per-mode kappa ladder + combine demotions, so
+            # the resumed trajectory matches the killed run even
+            # mid-recovery
+            mode_cfgs = [
+                dataclasses.replace(cfg, kappa=kap, combine=comb)
+                for kap, comb in zip(resume_state["kappas"],
+                                     resume_state["combines"])
+            ]
+            kkt_hist = list(resume_state["kkt_history"])
+            ll_hist = list(resume_state["loglik_history"])
+            inner_hist = list(resume_state["inner_iters"])
+            rebalances = list(resume_state.get("rebalances") or [])
+            recoveries.extend(RecoveryEvent(**r) for r in
+                              resume_state.get("recoveries", []))
             recoveries.append(RecoveryEvent(
-                "checkpoint_corrupt", outer=0,
-                detail={"error": str(e), "quarantined": qpath},
+                "resume", outer=start_outer,
+                detail={"path": resume_from},
             ))
-            resume_state = None
 
-    start_outer = 0
-    kkt_hist: list = []
-    ll_hist: list = []
-    inner_hist: list = []
-    rebalances: list = []
-    if resume_state is None:
-        strategies, layouts, policies, locals_ = _resolve_mode_policies(
-            cfg, mvs, factors, lam
-        )
-        # per-mode effective config: the kappa ladder and the combine
-        # demotion mutate these without touching the caller's cfg
-        mode_cfgs = [cfg] * n_modes
-    else:
-        start_outer = int(resume_state["outer"])
-        factors = [jnp.asarray(f) for f in resume_state["factors"]]
-        lam = jnp.asarray(resume_state["lam"])
-        strategies = list(resume_state["strategies"])
-        locals_ = list(resume_state["locals"])
-        policies = [PhiPolicy(**p) if p else None
-                    for p in resume_state["policies"]]
-        rb_bounds = {int(k): v
-                     for k, v in resume_state.get("rb_bounds", {}).items()}
-        layouts = _restore_mode_layouts(
-            mvs, strategies, policies, list(resume_state["mode_shards"]),
-            rb_bounds, shape=t.shape,
-            mode_grids=resume_state.get("mode_grids"),
-        )
-        # restore the per-mode kappa ladder + combine demotions, so the
-        # resumed trajectory matches the killed run even mid-recovery
-        mode_cfgs = [
-            dataclasses.replace(cfg, kappa=kap, combine=comb)
-            for kap, comb in zip(resume_state["kappas"],
-                                 resume_state["combines"])
-        ]
-        kkt_hist = list(resume_state["kkt_history"])
-        ll_hist = list(resume_state["loglik_history"])
-        inner_hist = list(resume_state["inner_iters"])
-        rebalances = list(resume_state.get("rebalances") or [])
-        recoveries.extend(RecoveryEvent(**r)
-                          for r in resume_state.get("recoveries", []))
-        recoveries.append(RecoveryEvent(
-            "resume", outer=start_outer, detail={"path": resume_from},
-        ))
-
-    pigs = [mode_pi_gather(mvs[n], layouts[n], cfg.shard_pi)
-            for n in range(n_modes)]
-    updates, gathers = [], []
-    for n in range(n_modes):
-        upd, gat = _make_mode_update(mvs[n], mode_cfgs[n], strategies[n],
-                                     layouts[n], locals_[n], pig=pigs[n])
-        updates.append(upd)
-        gathers.append(gat)
+        with trace.span("cpapr.build"):
+            pigs = [mode_pi_gather(mvs[n], layouts[n], cfg.shard_pi)
+                    for n in range(n_modes)]
+            updates, gathers = [], []
+            for n in range(n_modes):
+                upd, gat = _make_mode_update(mvs[n], mode_cfgs[n],
+                                             strategies[n], layouts[n],
+                                             locals_[n], pig=pigs[n])
+                updates.append(upd)
+                gathers.append(gat)
 
     def _rebuild(n: int) -> None:
         """Re-derive mode ``n``'s gather maps + jitted update from its
@@ -1379,20 +1445,25 @@ def cpapr_mu(
         failures demote one rung and retry with bounded backoff."""
         for attempt in range(cfg.max_demotions + 1):
             try:
-                return _invoke(outer, n, factors, lam)
+                # the span holds the dispatch, and the tracing and
+                # lowering of a mode update's first call
+                with trace.span("cpapr.mode_update", mode=n,
+                                strategy=strategies[n]):
+                    return _invoke(outer, n, factors, lam)
             except Exception as e:
                 kind = resilience.classify_failure(e)
                 if kind is None or attempt >= cfg.max_demotions:
                     raise
-                detail = _demote(n, kind, e)
-                if detail is None:
-                    raise
-                recoveries.append(RecoveryEvent(
-                    f"demote_{kind}", outer=outer, mode=n, attempt=attempt,
-                    detail=detail,
-                ))
-                resilience.backoff_sleep(attempt, cfg.demote_backoff)
-                _rebuild(n)
+                with trace.span("cpapr.recover"):
+                    detail = _demote(n, kind, e)
+                    if detail is None:
+                        raise
+                    recoveries.append(RecoveryEvent(
+                        f"demote_{kind}", outer=outer, mode=n,
+                        attempt=attempt, detail=detail,
+                    ))
+                    resilience.backoff_sleep(attempt, cfg.demote_backoff)
+                    _rebuild(n)
         raise AssertionError("unreachable")  # pragma: no cover
 
     def _escalate_kappa(n: int) -> None:
@@ -1416,7 +1487,7 @@ def cpapr_mu(
         sub-problems are also re-keyed under assignment-aware cache keys
         so future cold starts of this assignment hit.  A measuring tuner
         is deliberately skipped: grid-searching timed probes inside the
-        solve would stall it and distort ``CPAPRResult.seconds``.
+        solve would stall its sweeps.
         """
         tuner = cfg.autotuner if cfg.policy == "auto" else None
         rekey = tuner is not None and not getattr(tuner, "measure", True)
@@ -1500,99 +1571,108 @@ def cpapr_mu(
         })
 
     converged = False
-    t0 = time.perf_counter()
     n_outer = start_outer
     k = start_outer
     while k < cfg.max_outer:
         n_outer = k + 1
-        # sweep-start snapshot: the guards restore it (and redo the whole
-        # sweep) when any mode's state went numerically bad — mode
-        # updates are deterministic in (factors, lam), so a redone sweep
-        # is bitwise the sweep an uninterrupted run would have produced
-        snap_factors, snap_lam = list(factors), lam
-        ll = None
-        for sweep_attempt in range(cfg.guard_retries + 1):
-            # the shared pure sweep body (also the service's entry point);
-            # per-mode guard booleans stay ON DEVICE during the sweep:
-            # syncing them per mode would serialize the async factor
-            # epilogues / owner gathers the solver pipelines, so they are
-            # read once at sweep end when those buffers are complete
-            # anyway (the read is then ~free)
-            out = sweep_step(
-                (factors, lam),
-                [partial(_run_mode, n_outer, n) for n in range(n_modes)],
-                guard=cfg.guard,
-            )
-            factors, lam, bad = out.factors, out.lam, out.bad
-            worst = float(out.worst) if out.worst is not None else 0.0
-            inner_total = int(out.inner_total)
-            if not bad:
-                if cfg.track_loglik:
-                    ll = float(poisson_loglik(
-                        t, KTensor(lam, tuple(factors)), cfg.eps
-                    ))
-                if not cfg.guard or ll is None or math.isfinite(ll):
-                    break
-                # whole-sweep guard: per-mode states passed but the joint
-                # model mass went non-finite — escalate every mode
-                recoveries.append(RecoveryEvent(
-                    "loglik_guard", outer=n_outer, attempt=sweep_attempt,
-                    detail={"loglik": ll},
-                ))
-                bad = list(range(n_modes))
-            else:
-                for n in bad:
+        with trace.span("cpapr.sweep", outer=n_outer):
+            # sweep-start snapshot: the guards restore it (and redo the
+            # whole sweep) when any mode's state went numerically bad —
+            # mode updates are deterministic in (factors, lam), so a
+            # redone sweep is bitwise the sweep an uninterrupted run
+            # would have produced
+            snap_factors, snap_lam = list(factors), lam
+            ll = None
+            for sweep_attempt in range(cfg.guard_retries + 1):
+                # the shared pure sweep body (also the service's entry
+                # point).  With the guard on, it reads each mode's KKT
+                # scalar on the host as the mode completes, and the
+                # modes' guard flags at sweep end; then the sweep's
+                # worst KKT, its inner iterations and the log-likelihood
+                # are read here.  Every such read is a host sync.
+                out = sweep_step(
+                    (factors, lam),
+                    [partial(_run_mode, n_outer, n) for n in range(n_modes)],
+                    guard=cfg.guard, counters=counters,
+                )
+                factors, lam, bad = out.factors, out.lam, out.bad
+                worst = counters.read(float, out.worst) \
+                    if out.worst is not None else 0.0
+                inner_total = counters.read(int, out.inner_total)
+                if not bad:
+                    if cfg.track_loglik:
+                        with trace.span("cpapr.loglik"):
+                            ll = counters.read(float, poisson_loglik(
+                                t, KTensor(lam, tuple(factors)), cfg.eps
+                            ))
+                    if not cfg.guard or ll is None or math.isfinite(ll):
+                        break
+                    # whole-sweep guard: per-mode states passed but the
+                    # joint model mass went non-finite — escalate every
+                    # mode
                     recoveries.append(RecoveryEvent(
-                        "nan_guard", outer=n_outer, mode=n,
-                        attempt=sweep_attempt,
-                        detail={"kappa": float(mode_cfgs[n].kappa)},
+                        "loglik_guard", outer=n_outer, attempt=sweep_attempt,
+                        detail={"loglik": ll},
                     ))
-            # restore last-good state and redo the sweep.  The first
-            # retry reruns as-is (transient fault); later retries climb
-            # the kappa ladder on the offending modes.
-            factors = list(snap_factors)
-            lam = snap_lam
-            if sweep_attempt >= 1:
-                for n in bad:
-                    _escalate_kappa(n)
-                    _rebuild(n)
-        else:
-            raise FloatingPointError(
-                f"CP-APR sweep {n_outer}: non-finite or negative state "
-                f"persisted through {cfg.guard_retries} guarded sweep "
-                f"retries (mode(s) {bad})"
-            )
-        if cfg.guard and sweep_attempt > 0:
-            # recovery done: drop any escalated scooch back to the
-            # configured kappa so the lift does not keep distorting
-            # every subsequent sweep
-            for n in range(n_modes):
-                if mode_cfgs[n].kappa != cfg.kappa:
-                    mode_cfgs[n] = dataclasses.replace(
-                        mode_cfgs[n], kappa=cfg.kappa
-                    )
-                    _rebuild(n)
-        kkt_hist.append(worst)
-        inner_hist.append(inner_total)
-        if ll is not None:
-            ll_hist.append(ll)
-        if worst <= cfg.tol:
-            converged = True
-            break
-        if (
-            cfg.rebalance_every > 0
-            and n_outer % cfg.rebalance_every == 0
-            and n_outer < cfg.max_outer
-        ):
-            _rebalance_modes(n_outer, rebalances)
-        if (
-            cfg.checkpoint_every > 0
-            and cfg.checkpoint_path
-            and n_outer % cfg.checkpoint_every == 0
-        ):
-            _write_checkpoint(n_outer)
+                    bad = list(range(n_modes))
+                else:
+                    for n in bad:
+                        recoveries.append(RecoveryEvent(
+                            "nan_guard", outer=n_outer, mode=n,
+                            attempt=sweep_attempt,
+                            detail={"kappa": float(mode_cfgs[n].kappa)},
+                        ))
+                # restore last-good state and redo the sweep.  The first
+                # retry reruns as-is (transient fault); later retries
+                # climb the kappa ladder on the offending modes.
+                with trace.span("cpapr.recover"):
+                    factors = list(snap_factors)
+                    lam = snap_lam
+                    if sweep_attempt >= 1:
+                        for n in bad:
+                            _escalate_kappa(n)
+                            _rebuild(n)
+            else:
+                raise FloatingPointError(
+                    f"CP-APR sweep {n_outer}: non-finite or negative state "
+                    f"persisted through {cfg.guard_retries} guarded sweep "
+                    f"retries (mode(s) {bad})"
+                )
+            if cfg.guard and sweep_attempt > 0:
+                # recovery done: drop any escalated scooch back to the
+                # configured kappa so the lift does not keep distorting
+                # every subsequent sweep
+                with trace.span("cpapr.recover"):
+                    for n in range(n_modes):
+                        if mode_cfgs[n].kappa != cfg.kappa:
+                            mode_cfgs[n] = dataclasses.replace(
+                                mode_cfgs[n], kappa=cfg.kappa
+                            )
+                            _rebuild(n)
+            kkt_hist.append(worst)
+            inner_hist.append(inner_total)
+            counters.add("sweeps")
+            counters.add("inner", inner_total)
+            if ll is not None:
+                ll_hist.append(ll)
+            if worst <= cfg.tol:
+                converged = True
+                break
+            if (
+                cfg.rebalance_every > 0
+                and n_outer % cfg.rebalance_every == 0
+                and n_outer < cfg.max_outer
+            ):
+                with trace.span("cpapr.rebalance"):
+                    _rebalance_modes(n_outer, rebalances)
+            if (
+                cfg.checkpoint_every > 0
+                and cfg.checkpoint_path
+                and n_outer % cfg.checkpoint_every == 0
+            ):
+                with trace.span("cpapr.checkpoint"):
+                    _write_checkpoint(n_outer)
         k += 1
-    seconds = time.perf_counter() - t0
     return CPAPRResult(
         ktensor=KTensor(lam=lam, factors=tuple(factors)),
         n_outer=n_outer,
@@ -1600,7 +1680,6 @@ def cpapr_mu(
         loglik_history=ll_hist,
         inner_iters=inner_hist,
         converged=converged,
-        seconds=seconds,
         policies=policies if cfg.policy == "auto" else None,
         rebalances=rebalances or None,
         recoveries=recoveries or None,

@@ -253,7 +253,8 @@ def _phi_blocked_padded(layout: BlockedLayout, vals, pi, b, eps, perturb=None):
     Returns the *padded* (n_rows_pad, R) result, mirroring the kernel's
     output window; :func:`_phi_blocked` slices to n_rows.
     """
-    b_pad = jnp.pad(b, ((0, layout.n_rows_pad - b.shape[0]), (0, 0)))
+    with jax.named_scope("cpapr.layout"):
+        b_pad = jnp.pad(b, ((0, layout.n_rows_pad - b.shape[0]), (0, 0)))
     return _phi_blocked_core(
         vals,
         pi,
@@ -269,7 +270,9 @@ def _phi_blocked_padded(layout: BlockedLayout, vals, pi, b, eps, perturb=None):
 
 
 def _phi_blocked(layout: BlockedLayout, vals, pi, b, eps, perturb=None):
-    return _phi_blocked_padded(layout, vals, pi, b, eps, perturb)[: layout.n_rows]
+    phi_pad = _phi_blocked_padded(layout, vals, pi, b, eps, perturb)
+    with jax.named_scope("cpapr.layout"):
+        return phi_pad[: layout.n_rows]
 
 
 # ---------------------------------------------------------------------------
@@ -540,7 +543,9 @@ def phi_from_rows(
         layout, vals_e, pi_e = _resolve_layout(
             rows, n_rows, layout, vals, pi, vals_e, pi_e
         )
-        return phi_ops.phi_blocked(layout, vals_e, pi_e, b, float(eps))[:n_rows]
+        phi_pad = phi_ops.phi_blocked(layout, vals_e, pi_e, b, float(eps))
+        with jax.named_scope("cpapr.layout"):
+            return phi_pad[:n_rows]
     if strategy == "dense":
         if perturb is not None:
             raise ValueError("perturb is not supported for strategy='dense'")
@@ -599,8 +604,9 @@ def _mu_epilogue(b: jax.Array, phi: jax.Array, tol) -> tuple:
     ``B`` is left untouched on the iteration that detects convergence
     (viol <= tol), matching Chi & Kolda's check-before-update semantics.
     """
-    viol = jnp.max(jnp.abs(jnp.minimum(b, 1.0 - phi)))
-    return jnp.where(viol > tol, b * phi, b), viol
+    with jax.named_scope("cpapr.epilogue"):
+        viol = jnp.max(jnp.abs(jnp.minimum(b, 1.0 - phi)))
+        return jnp.where(viol > tol, b * phi, b), viol
 
 
 def phi_mu_step(
@@ -657,9 +663,11 @@ def phi_mu_step(
         # padded region of B/Phi is zero, so it adds |min(0, 1)| = 0 to the
         # violation max and nothing to B*Phi.
         phi_pad = _phi_blocked_padded(layout, vals_e, pi_e, b, eps)
-        b_pad = jnp.pad(b, ((0, layout.n_rows_pad - b.shape[0]), (0, 0)))
+        with jax.named_scope("cpapr.layout"):
+            b_pad = jnp.pad(b, ((0, layout.n_rows_pad - b.shape[0]), (0, 0)))
         b_new_pad, viol = _mu_epilogue(b_pad, phi_pad, tol)
-        return b_new_pad[:n_rows], viol
+        with jax.named_scope("cpapr.layout"):
+            return b_new_pad[:n_rows], viol
     if strategy == "pallas":
         from repro.kernels.phi import ops as phi_ops
 
@@ -667,7 +675,10 @@ def phi_mu_step(
             rows, n_rows, layout, vals, pi, vals_e, pi_e
         )
         mu_pad, viol = phi_ops.phi_mu_blocked(layout, vals_e, pi_e, b, eps)
-        return jnp.where(viol > tol, mu_pad[:n_rows], b), viol
+        with jax.named_scope("cpapr.layout"):
+            mu = mu_pad[:n_rows]
+        with jax.named_scope("cpapr.epilogue"):
+            return jnp.where(viol > tol, mu, b), viol
     if strategy == "dense":
         from repro.kernels.dense import ops as dense_ops
 

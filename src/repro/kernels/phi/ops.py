@@ -25,16 +25,19 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# The lane pads, the (N, 1) reshapes and the slices back from the padded
+# window are named cpapr.layout; the kernel call is the caller's cpapr.phi.
 def _pad_inputs(layout: BlockedLayout, vals_e, pi_e, b):
     dt = check_kernel_dtype("phi_mu_blocked", vals_e, pi_e, b)
     r = pi_e.shape[1]
     r_pad = round_up(r, 128)
     n_rows_pad = layout.n_rows_pad
-    vals2 = vals_e.reshape(-1, 1)
-    lrow2 = jnp.asarray(layout.local_rows, jnp.int32).reshape(-1, 1)
-    pi_p = jnp.pad(pi_e, ((0, 0), (0, r_pad - r)))
-    b_p = jnp.pad(b, ((0, n_rows_pad - b.shape[0]), (0, r_pad - r)))
-    grid_rb = jnp.asarray(layout.grid_rb, jnp.int32)
+    with jax.named_scope("cpapr.layout"):
+        vals2 = vals_e.reshape(-1, 1)
+        lrow2 = jnp.asarray(layout.local_rows, jnp.int32).reshape(-1, 1)
+        pi_p = jnp.pad(pi_e, ((0, 0), (0, r_pad - r)))
+        b_p = jnp.pad(b, ((0, n_rows_pad - b.shape[0]), (0, r_pad - r)))
+        grid_rb = jnp.asarray(layout.grid_rb, jnp.int32)
     return vals2, lrow2, pi_p, b_p, grid_rb, r, r_pad, dt
 
 
@@ -65,10 +68,11 @@ def phi_blocked_arrays(
         interpret = _default_interpret()
     r = pi_e.shape[1]
     r_pad = round_up(r, 128)
-    vals2 = vals_e.reshape(-1, 1)
-    lrow2 = local_rows.astype(jnp.int32).reshape(-1, 1)
-    pi_p = jnp.pad(pi_e, ((0, 0), (0, r_pad - r)))
-    b_p = jnp.pad(b_win, ((0, 0), (0, r_pad - r)))
+    with jax.named_scope("cpapr.layout"):
+        vals2 = vals_e.reshape(-1, 1)
+        lrow2 = local_rows.astype(jnp.int32).reshape(-1, 1)
+        pi_p = jnp.pad(pi_e, ((0, 0), (0, r_pad - r)))
+        b_p = jnp.pad(b_win, ((0, 0), (0, r_pad - r)))
     call = phi_pallas_call(
         n_grid=grid_rb.shape[0],
         block_nnz=block_nnz,
@@ -78,14 +82,15 @@ def phi_blocked_arrays(
         eps=float(eps),
         interpret=bool(interpret),
     )
-    return call(grid_rb.astype(jnp.int32), vals2, lrow2, pi_p, b_p)[
-        :, :r
-    ].astype(dt)
+    phi_pad = call(grid_rb.astype(jnp.int32), vals2, lrow2, pi_p, b_p)
+    with jax.named_scope("cpapr.layout"):
+        return phi_pad[:, :r].astype(dt)
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "eps", "interpret"))
 def _run(layout: BlockedLayout, vals_e, pi_e, b, eps: float, interpret: bool):
-    b_pad = jnp.pad(b, ((0, layout.n_rows_pad - b.shape[0]), (0, 0)))
+    with jax.named_scope("cpapr.layout"):
+        b_pad = jnp.pad(b, ((0, layout.n_rows_pad - b.shape[0]), (0, 0)))
     return phi_blocked_arrays(
         jnp.asarray(layout.grid_rb, jnp.int32),
         vals_e,
@@ -115,7 +120,10 @@ def _run_mu(layout: BlockedLayout, vals_e, pi_e, b, eps: float, interpret: bool)
         interpret=interpret,
     )
     mu_pad, kkt = call(grid_rb, vals2, lrow2, pi_p, b_p)
-    return mu_pad[:, :r].astype(dt), jnp.max(kkt)
+    with jax.named_scope("cpapr.layout"):
+        mu = mu_pad[:, :r].astype(dt)
+    with jax.named_scope("cpapr.epilogue"):  # the KKT max outside the kernel
+        return mu, jnp.max(kkt)
 
 
 def phi_blocked(

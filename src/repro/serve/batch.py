@@ -27,7 +27,6 @@ schedule to pay off.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -249,7 +248,6 @@ def batched_cpapr_mu(
             shape_max, max(t.nnz for t in tensors), rank
         )
 
-    t0 = time.perf_counter()
     if keys is None:
         keys = [jax.random.PRNGKey(j) for j in range(n_jobs)]
 
@@ -327,7 +325,6 @@ def batched_cpapr_mu(
                 n_outer[j] = k + 1
         done |= worst <= cfg.tol
         k += 1
-    seconds = time.perf_counter() - t0
 
     results = []
     for j, t in enumerate(tensors):
@@ -341,7 +338,6 @@ def batched_cpapr_mu(
             loglik_history=[],
             inner_iters=inner_hist[j],
             converged=bool(done[j]),
-            seconds=seconds / n_jobs,
         ))
     return results, bucket
 
