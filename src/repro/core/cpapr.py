@@ -723,6 +723,15 @@ def _make_mode_update(
         # by the scooch Phi and every fused inner iteration below.
         pi, vals_e, pi_e = hoisted_mode_inputs(mv, factors, strategy,
                                                lay, pig)
+        operands = None
+        if strategy == "pallas":
+            # The kernel's operands too: the (N, 1) reshapes and the
+            # 128-lane Pi, which replaces pi_e for the rest of the update.
+            from repro.kernels.phi import ops as phi_ops
+
+            operands = phi_ops.phi_operands(vals_e, pi_e, lay.local_rows,
+                                            lay.grid_rb)
+            vals_e = pi_e = None
 
         # --- scooch: lift inadmissible zeros (Alg. 1 line 3) --------------
         with jax.named_scope("cpapr.phi"):
@@ -741,6 +750,7 @@ def _make_mode_update(
                 local_strategy=local_strategy,
                 pi_gather=pig,
                 factors=factors if pig is not None else None,
+                operands=operands,
             )
         with jax.named_scope("cpapr.epilogue"):
             s = jnp.where((a_n < cfg.kappa_tol) & (phi0 > 1.0),
@@ -772,6 +782,7 @@ def _make_mode_update(
                     local_strategy=local_strategy,
                     pi_gather=pig,
                     factors=factors if pig is not None else None,
+                    operands=operands,
                 )
             return (i + 1, b_new, viol)
 

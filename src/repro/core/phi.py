@@ -40,7 +40,9 @@ the fused-epilogue kernel (one VMEM-resident pass instead of three HBM
 sweeps); the jnp strategies mirror the same math in a single traced
 expression so XLA fuses the elementwise epilogue into the reduction.
 ``vals_e``/``pi_e`` accept pre-expanded layout arrays so callers (the
-solver) can hoist the Pi gather out of the inner loop.
+solver) can hoist the Pi gather out of the inner loop; for ``pallas``,
+``operands`` hoists the kernel's own operands (``repro.kernels.phi.ops``)
+as well.
 """
 from __future__ import annotations
 
@@ -311,6 +313,24 @@ def _resolve_layout(rows, n_rows, layout, vals, pi, vals_e, pi_e):
     return layout, vals_e, pi_e
 
 
+def _resolve_pallas(rows, n_rows, layout, vals, pi, vals_e, pi_e, operands):
+    """Layout + the Pallas kernels' operands (``phi_ops.PhiOperands``).
+
+    Prepared ``operands`` (the solver builds them once per mode update)
+    pass through untouched; otherwise they are built here, once per call,
+    from :func:`_resolve_layout`'s expansion.
+    """
+    from repro.kernels.phi import ops as phi_ops
+
+    if operands is None or layout is None:
+        layout, vals_e, pi_e = _resolve_layout(
+            rows, n_rows, layout, vals, pi, vals_e, pi_e
+        )
+        operands = phi_ops.phi_operands(vals_e, pi_e, layout.local_rows,
+                                        layout.grid_rb)
+    return layout, operands
+
+
 def _dense_operands(dense, factors, b=None):
     """Kernel operands ``(x, c, a)`` for the dense tier.
 
@@ -504,6 +524,7 @@ def phi_from_rows(
     factors=None,
     combine: str = "psum",
     dense=None,
+    operands=None,
 ) -> jax.Array:
     """Phi^(n) from pre-gathered Pi rows.  ``rows`` sorted unless 'scatter'.
 
@@ -513,7 +534,10 @@ def phi_from_rows(
 
     For ``blocked``/``pallas``, optional ``vals_e``/``pi_e`` are the
     layout-expanded arrays (see :func:`expand_to_layout`); pass them to
-    skip per-call re-expansion.  For ``sharded``, ``layout`` is a
+    skip per-call re-expansion.  For ``pallas``, ``operands`` (a
+    ``repro.kernels.phi.ops.PhiOperands`` on ``layout``) go to the kernel
+    as they are, and ``vals_e``/``pi_e`` are not read; without them the
+    operands are built per call.  For ``sharded``, ``layout`` is a
     :class:`ShardedBlockedLayout`, ``vals_e``/``pi_e`` come from
     :func:`expand_to_shards`, and ``mesh`` (optional) places the shards on
     real devices with a psum combine — without a mesh the same schedule is
@@ -540,10 +564,10 @@ def phi_from_rows(
     if strategy == "pallas":
         from repro.kernels.phi import ops as phi_ops
 
-        layout, vals_e, pi_e = _resolve_layout(
-            rows, n_rows, layout, vals, pi, vals_e, pi_e
+        layout, operands = _resolve_pallas(
+            rows, n_rows, layout, vals, pi, vals_e, pi_e, operands
         )
-        phi_pad = phi_ops.phi_blocked(layout, vals_e, pi_e, b, float(eps))
+        phi_pad = phi_ops.phi_blocked(layout, operands, b, float(eps))
         with jax.named_scope("cpapr.layout"):
             return phi_pad[:n_rows]
     if strategy == "dense":
@@ -627,6 +651,7 @@ def phi_mu_step(
     factors=None,
     combine: str = "psum",
     dense=None,
+    operands=None,
 ) -> tuple:
     """One fused CP-APR inner MU step: ``(B', viol)`` in a single pass.
 
@@ -644,6 +669,7 @@ def phi_mu_step(
     (bitwise-identical ``(B', viol)``); the solver's inner loop uses the
     owner-stacked carry directly via
     ``repro.core.distributed.phi_mu_sharded_owner``.
+    ``operands`` as for :func:`phi_from_rows`.
     This is the entry point ``cpapr_mu``'s inner ``lax.while_loop`` calls.
     """
     eps = float(eps)
@@ -671,10 +697,10 @@ def phi_mu_step(
     if strategy == "pallas":
         from repro.kernels.phi import ops as phi_ops
 
-        layout, vals_e, pi_e = _resolve_layout(
-            rows, n_rows, layout, vals, pi, vals_e, pi_e
+        layout, operands = _resolve_pallas(
+            rows, n_rows, layout, vals, pi, vals_e, pi_e, operands
         )
-        mu_pad, viol = phi_ops.phi_mu_blocked(layout, vals_e, pi_e, b, eps)
+        mu_pad, viol = phi_ops.phi_mu_blocked(layout, operands, b, eps)
         with jax.named_scope("cpapr.layout"):
             mu = mu_pad[:n_rows]
         with jax.named_scope("cpapr.epilogue"):

@@ -629,8 +629,16 @@ def _jit_mu_burst(rows, vals, pi, b, vals_e, pi_e, n_rows, strategy, layout,
     Mirrors the loop shape of ``cpapr_mu``'s inner solve — same carried
     state, same per-step fused ``phi_mu_step`` — with ``tol=-1`` so the
     update always applies and B keeps evolving across iterations (the
-    revisit pattern a one-shot probe never exercises).
+    revisit pattern a one-shot probe never exercises).  As in the
+    solver, a Pallas candidate's kernel operands are built once, before
+    the loop.
     """
+    operands = None
+    if strategy == "pallas":
+        from repro.kernels.phi import ops as phi_ops
+
+        operands = phi_ops.phi_operands(vals_e, pi_e, layout.local_rows,
+                                        layout.grid_rb)
 
     def cond(state):
         i, _, viol = state
@@ -649,6 +657,7 @@ def _jit_mu_burst(rows, vals, pi, b, vals_e, pi_e, n_rows, strategy, layout,
             layout=layout,
             vals_e=vals_e,
             pi_e=pi_e,
+            operands=operands,
         )
         return (i + 1, b_new, viol)
 
